@@ -33,15 +33,10 @@ def main():
                           chunk_tokens=512)
 
     # offline TTFT profile -> polynomial predictor (paper §6.4)
-    xs, ys = [], []
-    for n in (128, 512, 1024, 2048, 4096):
-        toks = jnp.zeros((1, n), jnp.int32)
-        ex.run_all(ex.start(toks))                     # warm compile
-        t0 = time.monotonic()
-        ex.run_all(ex.start(toks))
-        xs.append(n)
-        ys.append(time.monotonic() - t0)
-        print(f"  profile: {n:5d} tokens -> {ys[-1]*1e3:7.1f} ms")
+    xs = [128, 512, 1024, 2048, 4096]
+    ys = ex.profile(xs)
+    for n, y in zip(xs, ys):
+        print(f"  profile: {n:5d} tokens -> {y*1e3:7.1f} ms")
     pred = TTFTPredictor.fit(xs, ys)
 
     core = SchedulerCore(predictor=pred, policy="s-edf",

@@ -129,7 +129,6 @@ def run_real(args):
     import time
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.configs.base import get_tiny_config
@@ -148,14 +147,8 @@ def run_real(args):
     params = init_params(cfg, jax.random.PRNGKey(0))
     ex = SegmentedPrefill(params, cfg, max_seq=max_seq, granularity="op",
                           chunk_tokens=512)
-    xs, ys = [], []
-    for n in (256, 1024, 2048):               # offline TTFT profile + warmup
-        toks = jnp.zeros((1, n), jnp.int32)
-        ex.run_all(ex.start(toks))
-        t0 = time.monotonic()
-        ex.run_all(ex.start(toks))
-        xs.append(n)
-        ys.append(time.monotonic() - t0)
+    xs = [256, 1024, 2048]                    # offline TTFT profile + warmup
+    ys = ex.profile(xs)
     pred = TTFTPredictor.fit(xs, ys)
 
     policy = args.policy if args.policy != "all" else "least-loaded"

@@ -10,7 +10,6 @@ import dataclasses
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_tiny_config
@@ -76,15 +75,8 @@ def main():
     params = init_params(CFG, jax.random.PRNGKey(0))
     ex = SegmentedPrefill(params, CFG, max_seq=MAX_SEQ, granularity="op",
                           chunk_tokens=512)
-    xs, ys = [], []
-    for n in (256, 1024, 2048, 4096):
-        toks = jnp.zeros((1, n), jnp.int32)
-        ex.run_all(ex.start(toks))
-        t0 = time.monotonic()
-        ex.run_all(ex.start(toks))
-        xs.append(n)
-        ys.append(time.monotonic() - t0)
-    pred = TTFTPredictor.fit(xs, ys)
+    xs = [256, 1024, 2048, 4096]
+    pred = TTFTPredictor.fit(xs, ex.profile(xs))
 
     policies = ["s-edf", "fcfs"] if args.policy == "both" else [args.policy]
     for policy in policies:

@@ -184,12 +184,8 @@ def flash_verify_attention(
         ],
     )
 
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except AttributeError:
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,
@@ -260,12 +256,8 @@ def flash_decode_attention(
         ],
     )
 
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except AttributeError:
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,

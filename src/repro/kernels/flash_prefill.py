@@ -166,12 +166,8 @@ def flash_prefill_attention(
         ],
     )
 
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-    except AttributeError:  # older naming
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,

@@ -5,8 +5,8 @@
   "pallas_interpret"  — same kernel body, interpreted on CPU (tests)
   "xla"               — blocked pure-JAX flash (dry-run lowering path)
   "ref"               — naive oracle (small shapes only)
-On this container (CPU) the default is interpret for small shapes and xla
-otherwise; on a TPU runtime the default is the kernel.
+`default_impl()` chooses by platform: "pallas" when JAX's default backend is
+a TPU, "xla" on any other backend. Tests select "pallas_interpret" by name.
 """
 from __future__ import annotations
 

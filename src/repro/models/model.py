@@ -14,6 +14,7 @@ stacked layer params (compile time independent of depth — required for the
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -293,6 +294,26 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
 
 def param_count(params: Params) -> int:
     return sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+
+
+def params_device(params: Params) -> jax.Device:
+    """The device holding a single-device params pytree: a serving instance
+    allocates its state there and runs its worker threads `on_device` of
+    it. Params that hold no device array (a stub model) place nothing, so
+    they get the default device."""
+    leaves = jax.tree.leaves(params)
+    if not leaves or not isinstance(leaves[0], jax.Array):
+        return jax.devices()[0]
+    return next(iter(leaves[0].devices()))
+
+
+def on_device(device: jax.Device):
+    """Context that places new arrays on `device`. A no-op on the process's
+    default device: entering `jax.default_device` changes jit's cache key,
+    so a program warmed up outside the context would compile again."""
+    if device == jax.devices()[0]:
+        return contextlib.nullcontext()
+    return jax.default_device(device)
 
 
 # ---------------------------------------------------------------------------

@@ -28,8 +28,9 @@ first-token logits.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -337,3 +338,18 @@ class SegmentedPrefill:
         while not task.done:
             self.step(task)
         return task.logits
+
+    def profile(self, lengths: Sequence[int]) -> List[float]:
+        """Offline TTFT profile (paper §6.4): wall seconds of one
+        uninterrupted single-prompt prefill per length in `lengths`, each
+        timed after a warm-up run that compiles its shapes. Both runs end
+        in `block_until_ready`, so the time covers the device work and not
+        only the dispatch."""
+        seconds = []
+        for n in lengths:
+            toks = jnp.zeros((1, n), jnp.int32)
+            jax.block_until_ready(self.run_all(self.start(toks)))
+            t0 = time.monotonic()
+            jax.block_until_ready(self.run_all(self.start(toks)))
+            seconds.append(time.monotonic() - t0)
+        return seconds

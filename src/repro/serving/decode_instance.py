@@ -59,6 +59,11 @@ periodic re-probe); a step in which no row drafts runs the PLAIN jitted
 step, so the adversarial low-accept regime degrades to ~plain cost
 (benchmarks/fig27_spec_decode.py gates both regimes). ``spec_decode=False``
 (the default) leaves every code path byte-identical to before.
+
+Device: the instance lives on the device that holds its params. `submit`
+moves a handed-off KV cache onto it (a prefill on another chip hands over
+across chips), the paged pool is allocated there, and the worker thread runs
+`on_device` of it.
 """
 from __future__ import annotations
 
@@ -76,7 +81,8 @@ from repro.core.predictor import DecodeStepPredictor
 from repro.core.request import Request
 from repro.core.scheduler import DecodeEntry, DecodeSchedulerCore
 from repro.models.model import (decode_step, decode_step_ragged,
-                                decode_verify_ragged, supports_ragged_decode)
+                                decode_verify_ragged, on_device,
+                                params_device, supports_ragged_decode)
 from repro.serving.kvcache import PagedKVCache
 
 # sequence id of the pool slot padding rows write into / gather from — never
@@ -162,6 +168,7 @@ class DecodeInstance:
                 f"spec_decode needs the batched verify step, unsupported "
                 f"for family {cfg.family!r}")
         self.params = params
+        self.device = params_device(params)
         self.cfg = cfg
         self.decode_tokens = decode_tokens
         self.decode_max_batch = max(decode_max_batch, 1)
@@ -276,6 +283,9 @@ class DecodeInstance:
             # count the SAME tokens, or slack estimates skew by their ratio
             job.target = req.output_tokens if req.output_tokens > 0 \
                 else self.decode_tokens
+        if job.cache is not None:
+            # the handoff: a no-op for a cache already on this device
+            job.cache = jax.device_put(job.cache, self.device)
         with self._cv:
             job.order = self._order
             self._order += 1
@@ -388,12 +398,13 @@ class DecodeInstance:
     def _supervised(self, loop: Callable[[], None]) -> None:
         """Worker wrapper: exceptions strand the instance instead of
         silently killing the thread; the thread survives for restart()."""
-        while True:
-            try:
-                loop()
-                return                      # clean shutdown exit
-            except Exception as exc:
-                self._on_worker_failure(exc)
+        with on_device(self.device):
+            while True:
+                try:
+                    loop()
+                    return                  # clean shutdown exit
+                except Exception as exc:
+                    self._on_worker_failure(exc)
 
     def _check_inject(self) -> None:
         """Chaos hook, called at the token boundary: raise a pending
@@ -672,22 +683,24 @@ class DecodeInstance:
         it is in neither the waiting list nor a slot). The dense view is
         padded to cover the REMAINING decode so a slot-cap-1 receiver (dense
         `decode_step`, which writes at `pos`) never runs off the cache."""
-        rid = job.request.rid
-        with self._kv_lock:
-            k, v, length = self.kv.gather(rid)
-            k = jax.block_until_ready(k)     # copy out before the worker's
-            v = jax.block_until_ready(v)     # next donated scatter runs
-            self.kv.free(rid)
-        kv_len = job.base_len + job.tokens_done
-        need = kv_len + max(job.target - job.tokens_done, 0) + 1
-        keep = max(kv_len, int(length))
-        k, v = k[:, None, :keep], v[:, None, :keep]
-        if keep < need:
-            pad = [(0, 0)] * k.ndim
-            pad[2] = (0, need - keep)
-            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-        job.cache = {"k": k, "v": v, "pos": jnp.asarray(kv_len, jnp.int32)}
-        self._in_pool.discard(rid)
+        # runs on the migrating thread: keep the pool's arrays on their device
+        with on_device(self.device):
+            rid = job.request.rid
+            with self._kv_lock:
+                k, v, length = self.kv.gather(rid)
+                k = jax.block_until_ready(k)     # copy out before the worker's
+                v = jax.block_until_ready(v)     # next donated scatter runs
+                self.kv.free(rid)
+            kv_len = job.base_len + job.tokens_done
+            need = kv_len + max(job.target - job.tokens_done, 0) + 1
+            keep = max(kv_len, int(length))
+            k, v = k[:, None, :keep], v[:, None, :keep]
+            if keep < need:
+                pad = [(0, 0)] * k.ndim
+                pad[2] = (0, need - keep)
+                k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+            job.cache = {"k": k, "v": v, "pos": jnp.asarray(kv_len, jnp.int32)}
+            self._in_pool.discard(rid)
 
     def _plan_locked(self, now: float) -> List[DecodeJob]:
         """Token-boundary admission + eviction DECISIONS (caller holds _cv):

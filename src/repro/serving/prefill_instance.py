@@ -13,6 +13,11 @@ COMPLETION the computed suffix KV is scattered into the fresh blocks, the
 full blocks are registered in the trie, and the sequence is released
 (refcount decrement: its blocks stay CACHED for the next matching prompt,
 LRU-evicted only under capacity pressure).
+
+Device: the instance lives on the device that holds its executor's params.
+Its prefill state and prefix pool are allocated there, and both worker
+threads run `on_device` of it, so instances on different chips of one host
+keep their arrays apart.
 """
 from __future__ import annotations
 
@@ -27,6 +32,7 @@ from repro.core.events import Event, EventKind, EventMonitor
 from repro.core.prefixcache import block_keys
 from repro.core.request import Request, RequestState
 from repro.core.scheduler import Action, SchedulerCore
+from repro.models.model import on_device, params_device
 from repro.models.segments import SegmentedPrefill
 from repro.serving.kvcache import PagedKVCache
 from repro.serving.pool import ExecTask, ExecutionPool
@@ -55,17 +61,19 @@ class PrefillInstance:
         self.executor = executor or SegmentedPrefill(
             params, cfg, max_seq=max_seq, granularity=granularity,
             chunk_tokens=chunk_tokens, attn_impl=attn_impl)
+        self.device = params_device(self.executor.params)
 
         # prefix-sharing prompt KV cache (None = disabled, the default)
         self.kv: Optional[PagedKVCache] = None
         self.kv_block_size = kv_block_size
         if prefix_share:
-            self.kv = PagedKVCache(
-                cfg.num_layers, prefix_cache_blocks, kv_block_size,
-                cfg.num_kv_heads, cfg.resolved_head_dim,
-                dtype=self.executor.cache_dtype, prefix_share=True,
-                host_cache_blocks=host_cache_blocks,
-                disk_cache_blocks=disk_cache_blocks)
+            with on_device(self.device):
+                self.kv = PagedKVCache(
+                    cfg.num_layers, prefix_cache_blocks, kv_block_size,
+                    cfg.num_kv_heads, cfg.resolved_head_dim,
+                    dtype=self.executor.cache_dtype, prefix_share=True,
+                    host_cache_blocks=host_cache_blocks,
+                    disk_cache_blocks=disk_cache_blocks)
         # guards self.kv: the scheduler thread mutates it on every
         # arrival/completion while the Proxy probes it for affinity routing
         self._kv_lock = threading.Lock()
@@ -196,7 +204,8 @@ class PrefillInstance:
 
     # ---------------------------------------------------------------- worker
     def _step(self, task: ExecTask) -> bool:
-        return self.executor.step(task.prefill_task)
+        with on_device(self.device):
+            return self.executor.step(task.prefill_task)
 
     def _complete(self, task: ExecTask) -> None:
         if not self.healthy:
@@ -215,6 +224,10 @@ class PrefillInstance:
 
     # ------------------------------------------------------------- scheduler
     def _scheduler_loop(self) -> None:
+        with on_device(self.device):
+            self._schedule_events()
+
+    def _schedule_events(self) -> None:
         while not self._shutdown:
             ev = self.monitor.next_event(timeout=1.0)
             if ev is None:

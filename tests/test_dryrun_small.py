@@ -33,10 +33,9 @@ SCRIPT = textwrap.dedent("""
     if not ok:
         print(json.dumps({"status": "skipped", "reason": why}))
         sys.exit(0)
-    if multi_pod:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    else:
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+    mesh = jax.make_mesh((2,) * len(axes), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     rules = (shd.train_rules(multi_pod=multi_pod) if shape.kind == "train"
              else shd.serve_rules(multi_pod=multi_pod))
     with mesh, shd.use_sharding(mesh, rules):

@@ -208,7 +208,6 @@ def test_sim_shedding_rejects_only_doomed():
 
 jax = pytest.importorskip("jax")
 
-import jax.numpy as jnp                                 # noqa: E402
 
 from repro.configs.base import get_tiny_config          # noqa: E402
 from repro.models import init_params                    # noqa: E402
@@ -357,11 +356,7 @@ def test_runtime_hang_detected_by_watchdog(chaos_model):
     # warm full prefill pass of the largest request is the yardstick for
     # "an honest stall"; the period must dwarf it, and the injected hang
     # must dwarf the period so detection stays unambiguous.
-    toks = jnp.zeros((1, 256), jnp.int32)
-    ex.run_all(ex.start(toks))                      # warm (jit + pools)
-    t0 = time.monotonic()
-    ex.run_all(ex.start(toks))
-    wd = min(4.0, max(0.4, 25 * (time.monotonic() - t0)))
+    wd = min(4.0, max(0.4, 25 * ex.profile([256])[0]))
     hang = max(1.0, 2.5 * wd)
 
     def fault(proxy, insts, decs):

@@ -37,15 +37,8 @@ def served_model():
     # doubles as compile warm-up for the shapes the tests serve
     ex = SegmentedPrefill(params, CFG, max_seq=MAX_SEQ, granularity="op",
                           chunk_tokens=512)
-    xs, ys = [], []
-    for n in (128, 512, 1024, 2048, 4096):
-        toks = jnp.zeros((1, n), jnp.int32)
-        ex.run_all(ex.start(toks))          # warm compile
-        t0 = time.monotonic()
-        ex.run_all(ex.start(toks))
-        xs.append(n)
-        ys.append(time.monotonic() - t0)
-    pred = TTFTPredictor.fit(xs, ys, degree=2)
+    xs = [128, 512, 1024, 2048, 4096]
+    pred = TTFTPredictor.fit(xs, ex.profile(xs), degree=2)
     return params, pred, ex
 
 
