@@ -42,10 +42,8 @@ class EventMonitor:
     def __init__(self):
         self._q: "queue.Queue[Event]" = queue.Queue()
         self.rounds = 0                   # scheduling rounds triggered
-        self.counts = {k: 0 for k in EventKind}
 
     def publish(self, event: Event) -> None:
-        self.counts[event.kind] += 1
         self._q.put(event)
 
     def next_event(self, timeout: Optional[float] = None) -> Optional[Event]:

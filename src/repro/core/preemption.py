@@ -16,24 +16,26 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 
 class PreemptionSignal:
-    """Signal / ACK pair with blocking-time accounting."""
+    """Signal / ACK pair with blocking-time accounting. The signal and the
+    ACK are stamped on `time.monotonic_ns()`; `stamps` holds the last
+    acknowledged pair, from which the blocking time is computed."""
 
     def __init__(self):
         self._flag = threading.Event()
         self._ack = threading.Event()
         self._lock = threading.Lock()
-        self._signal_time: Optional[float] = None
-        self.blocking_times: List[float] = []
+        self._signal_ns: Optional[int] = None
+        self.stamps: Tuple[int, int] = (0, 0)
 
     # --- scheduler side -----------------------------------------------------
     def request_preemption(self) -> None:
         with self._lock:
             self._ack.clear()
-            self._signal_time = time.monotonic()
+            self._signal_ns = time.monotonic_ns()
             self._flag.set()
 
     def wait_ack(self, timeout: Optional[float] = None) -> bool:
@@ -45,7 +47,7 @@ class PreemptionSignal:
         """Withdraw an un-acknowledged signal (e.g. task completed first)."""
         with self._lock:
             self._flag.clear()
-            self._signal_time = None
+            self._signal_ns = None
 
     # --- runtime side (called at every operator boundary) --------------------
     def check(self) -> bool:
@@ -53,14 +55,16 @@ class PreemptionSignal:
         return self._flag.is_set()
 
     def consume_and_ack(self) -> float:
-        """Unset the signal, record blocking time, ACK. Returns blocking dt."""
+        """Unset the signal, stamp the ACK, ACK. Returns the blocking time
+        in seconds (0 for an ACK with no signal stamped)."""
         with self._lock:
             self._flag.clear()
             dt = 0.0
-            if self._signal_time is not None:
-                dt = time.monotonic() - self._signal_time
-                self.blocking_times.append(dt)
-                self._signal_time = None
+            if self._signal_ns is not None:
+                ack_ns = time.monotonic_ns()
+                self.stamps = (self._signal_ns, ack_ns)
+                dt = (ack_ns - self._signal_ns) * 1e-9
+                self._signal_ns = None
         self._ack.set()
         return dt
 
@@ -78,13 +82,6 @@ class BlockingStats:
     @property
     def mean(self) -> float:
         return sum(self.samples) / len(self.samples) if self.samples else 0.0
-
-    @property
-    def p99(self) -> float:
-        if not self.samples:
-            return 0.0
-        s = sorted(self.samples)
-        return s[min(int(0.99 * len(s)), len(s) - 1)]
 
     @property
     def max(self) -> float:

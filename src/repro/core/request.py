@@ -64,6 +64,11 @@ class Request:
     retries: int = 0
     shed: bool = False
 
+    # serving-path tracing (repro.core.tracing): when Proxy.submit took the
+    # request, on time.monotonic_ns(), and the id of its `request` span
+    submit_ns: Optional[int] = None
+    trace_id: Optional[int] = None
+
     # outcome
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None

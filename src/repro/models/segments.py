@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.core.tracing import jit_named
 from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models.model import _project_qkv, embed_tokens, lm_head
@@ -183,16 +184,16 @@ class SegmentedPrefill:
         self.ops = op_names(cfg)
         op_fns = _make_op_fns(cfg, attn_impl)
 
-        # group ops into jitted segments according to granularity
+        # group ops into jitted segments according to granularity; each
+        # group's program is named after it (`prefill_attn`, `prefill_layer`)
         per_layer = [op_fns[name] for name in self.ops]
         if granularity == "op":
             groups: List[List[Callable]] = [[f] for f in per_layer]
-        elif granularity == "layer":
-            groups = [per_layer]
-        elif granularity.startswith("block"):
-            groups = [per_layer]           # layer group; block factor applied below
-        elif granularity == "whole":
-            groups = [per_layer]
+            self._group_names = list(self.ops)
+        elif granularity == "layer" or granularity.startswith("block") \
+                or granularity == "whole":
+            groups = [per_layer]           # block factor applied below
+            self._group_names = [granularity]
         else:
             raise ValueError(granularity)
 
@@ -202,21 +203,21 @@ class SegmentedPrefill:
         elif granularity == "whole":
             self._block_layers = cfg.num_layers
 
-        def make_segment(fns, n_layers):
+        def make_segment(fns, n_layers, name):
             def seg(pl_, st, l0, off):
                 for i in range(n_layers):
                     l = l0 + i
                     for f in fns:
                         st = f(pl_, st, l, off)
                 return st
-            return jax.jit(seg)
+            return jit_named(f"prefill_{name}", seg)
 
-        self._segments = [make_segment(g, self._block_layers) for g in groups]
+        self._segments = [make_segment(g, self._block_layers, name)
+                          for g, name in zip(groups, self._group_names)]
         self._segments_per_chunk = (
             (cfg.num_layers + self._block_layers - 1) // self._block_layers
             * len(self._segments))
 
-        @jax.jit
         def start_fn(params_, tokens, vision_embeds=None):
             h = embed_tokens(cfg, params_, tokens)
             if cfg.family == "vlm" and vision_embeds is not None:
@@ -224,15 +225,14 @@ class SegmentedPrefill:
                 h = h.at[:, :P_, :].set(vision_embeds.astype(h.dtype))
             return h
 
-        @jax.jit
         def head_fn(params_, h_full, lens):
             # per-request last valid position (batched requests are padded)
             B = h_full.shape[0]
             h_last = h_full[jnp.arange(B), lens - 1][:, None, :]
             return lm_head(cfg, params_, h_last)[:, 0]
 
-        self._start_fn = start_fn
-        self._head_fn = head_fn
+        self._start_fn = jit_named("prefill_embed", start_fn)
+        self._head_fn = jit_named("prefill_head", head_fn)
 
     # --- plan geometry -------------------------------------------------------
     def n_chunks(self, prompt_len: int, prefix_len: int = 0) -> int:
@@ -295,6 +295,16 @@ class SegmentedPrefill:
         lo = task.start_offset + chunk_idx * task.chunk
         hi = min(lo + task.chunk, task.prompt_len)
         return lo, hi
+
+    def describe(self, task: PrefillTask) -> Dict[str, Any]:
+        """The segment `step` dispatches next: its op group (`head` for the
+        LM head) and chunk index."""
+        seg_idx = task.cursor
+        if seg_idx >= task.total_segments - 1:
+            return {"op": "head", "chunk": task.n_chunks - 1}
+        chunk_idx, within = divmod(seg_idx, self._segments_per_chunk)
+        return {"op": self._group_names[within % len(self._segments)],
+                "chunk": chunk_idx}
 
     def step(self, task: PrefillTask) -> bool:
         """Dispatch the next segment. Returns True when the task completed.

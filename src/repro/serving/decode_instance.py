@@ -80,6 +80,7 @@ from repro.core.dispatch import DecodeCandidate, DecodeLoad
 from repro.core.predictor import DecodeStepPredictor
 from repro.core.request import Request
 from repro.core.scheduler import DecodeEntry, DecodeSchedulerCore
+from repro.core.tracing import TRACER, jit_named, now_ns, on
 from repro.models.model import (decode_step, decode_step_ragged,
                                 decode_verify_ragged, on_device,
                                 params_device, supports_ragged_decode)
@@ -139,6 +140,8 @@ class DecodeJob:
                                     # materializes it)
     probe_in: int = 0               # steps until a throttled stream re-probes
                                     # the drafter (spec_decode)
+    queued_ns: int = 0              # last submit on tracing.now_ns(),
+                                    # until the stream is next resident
 
 
 class DecodeInstance:
@@ -236,13 +239,13 @@ class DecodeInstance:
         self.draft_accepted = 0         # draft tokens committed
         self._accept_tps = 0.0          # aggregate tokens/step EMA fallback
                                         # (no step_pred attached)
-        self._step = jax.jit(
-            lambda p, t, c: decode_step(p, cfg, t, c))
-        self._step_ragged = jax.jit(
-            lambda p, t, kg, vg, kl: decode_step_ragged(
+        self._step = jit_named(
+            "decode_step_dense", lambda p, t, c: decode_step(p, cfg, t, c))
+        self._step_ragged = jit_named(
+            "decode_step", lambda p, t, kg, vg, kl: decode_step_ragged(
                 p, cfg, t, kg, vg, kl, attn_impl=attn_impl))
-        self._step_verify = jax.jit(
-            lambda p, t, kg, vg, kl: decode_verify_ragged(
+        self._step_verify = jit_named(
+            "decode_verify", lambda p, t, kg, vg, kl: decode_verify_ragged(
                 p, cfg, t, kg, vg, kl, attn_impl=attn_impl))
         # supervised-worker health (docs/ARCHITECTURE.md failure model): a
         # worker exception strands queued + resident jobs' REQUESTS back to
@@ -286,6 +289,7 @@ class DecodeInstance:
         if job.cache is not None:
             # the handoff: a no-op for a cache already on this device
             job.cache = jax.device_put(job.cache, self.device)
+        job.queued_ns = now_ns()
         with self._cv:
             job.order = self._order
             self._order += 1
@@ -511,9 +515,21 @@ class DecodeInstance:
             self.step_pred.observe(b, ctx, tbt)
 
     def _finish(self, job: DecodeJob, now: float) -> None:
-        job.request.finish_time = now
-        job.request.mean_tpot = (now - job.enqueued) / max(job.target, 1)
-        self.finished.append(job.request)
+        req = job.request
+        req.finish_time = now
+        req.mean_tpot = (now - job.enqueued) / max(job.target, 1)
+        self.finished.append(req)
+        if on() and req.submit_ns is not None:
+            TRACER.record("request", req.submit_ns, now_ns(), rid=req.rid,
+                          span_id=req.trace_id)
+
+    @staticmethod
+    def _took_slot(job: DecodeJob) -> None:
+        """The stream just took a slot: close its `decode.queue` span."""
+        if job.queued_ns and on():
+            TRACER.record("decode.queue", job.queued_ns, now_ns(),
+                          rid=job.request.rid, parent=job.request.trace_id)
+        job.queued_ns = 0
 
     # ------------------------------------- single-stream worker (slot cap 1)
     def _pick_next_locked(self, now: float) -> DecodeJob:
@@ -560,6 +576,7 @@ class DecodeInstance:
                     continue
                 job = self._pick_next_locked(self.clock())
                 self._resident[job.request.rid] = job
+                self._took_slot(job)
                 epoch = self._epoch
             start = job.first_token if job.next_token is None \
                 else job.next_token
@@ -666,8 +683,13 @@ class DecodeInstance:
                     # exceed the cap rather than wedge the instance
                     self.kv.grow(max(need_blocks, self.kv.num_blocks))
             self.kv.allocate(rid, need_tokens)
+            sp = TRACER.begin("decode.ingest", rid=rid,
+                              parent=job.request.trace_id, tokens=pos) \
+                if on() else None
             self.kv.write_prompt(rid, job.cache["k"][:, 0, :pos],
                                  job.cache["v"][:, 0, :pos])
+            if sp is not None:
+                TRACER.end(sp)
         # the handoff cache's pos covers prompt + already-decoded tokens
         # (a migrated-in mid-stream job has tokens_done > 0), while the kv
         # position is computed as base_len + tokens_done — subtract so the
@@ -769,6 +791,8 @@ class DecodeInstance:
             kv_lens[i] = j.base_len + j.tokens_done
             tokens[i] = j.first_token if j.next_token is None else j.next_token
         t0 = self.clock()
+        sp = TRACER.begin("decode.step", rid=tuple(seq_ids[:n]), rows=n,
+                          padded_rows=bb) if on() else None
         with self._kv_lock:
             # KV width bucket: power-of-two over the widest row's ALLOCATED
             # block count — gather_batch pads to at least the table width,
@@ -781,12 +805,27 @@ class DecodeInstance:
             width = 1
             while width < need_blocks:
                 width *= 2
+            if sp is not None:
+                part = TRACER.begin("decode.gather", rid=sp.span.rid,
+                                    parent=sp.id)
             k_g, v_g, _ = self.kv.gather_batch(seq_ids, width)
+            if sp is not None:
+                TRACER.end(part)
+                TRACER.count("decode.gather_bytes", k_g.nbytes + v_g.nbytes)
+                part = TRACER.begin("decode.compute", rid=sp.span.rid,
+                                    parent=sp.id)
             logits, k_new, v_new = self._step_ragged(
                 self.params, jnp.asarray(tokens), k_g, v_g,
                 jnp.asarray(kv_lens))
             next_tokens = np.asarray(jnp.argmax(logits, -1))
+            if sp is not None:
+                TRACER.end(part)
+                part = TRACER.begin("decode.scatter", rid=sp.span.rid,
+                                    parent=sp.id)
             self.kv.write_tokens(seq_ids, kv_lens.tolist(), k_new, v_new)
+            if sp is not None:
+                TRACER.end(part)
+                TRACER.end(sp, width=width, kv_tokens=int(kv_lens[:n].sum()))
         # the next token cannot start before the scatter completes: stamp the
         # step AFTER write_tokens so observed dt matches what
         # profile_step_times measures (the prior the EMA calibrates against)
@@ -965,9 +1004,13 @@ class DecodeInstance:
     def _run_batched(self) -> None:
         while True:
             with self._cv:
+                t_wait = 0
                 while not self._waiting and not self._resident \
                         and not self._shutdown and self._inject is None:
+                    t_wait = t_wait or now_ns()
                     self._cv.wait(0.1)
+                if t_wait and on():
+                    TRACER.record("decode.wait", t_wait, now_ns())
                 if self._shutdown and not self._waiting \
                         and not self._resident:
                     return
@@ -980,6 +1023,7 @@ class DecodeInstance:
                     self._admitting -= 1
                     if ok:
                         self._resident[job.request.rid] = job
+                        self._took_slot(job)
                     else:
                         self._waiting.append(job)
             with self._cv:
@@ -995,6 +1039,7 @@ class DecodeInstance:
                 with self._cv:
                     self._admitting -= 1
                     self._resident[force_job.request.rid] = force_job
+                    self._took_slot(force_job)
             with self._cv:
                 batch = sorted(self._resident.values(), key=lambda j: j.order)
             if not batch:

@@ -23,6 +23,7 @@ import jax
 
 from repro.core.preemption import BlockingStats, PreemptionSignal
 from repro.core.request import Request
+from repro.core.tracing import TRACER, now_ns, on, rids
 
 _task_ids = itertools.count()
 
@@ -35,6 +36,12 @@ class ExecTask:
     task_id: int = field(default_factory=lambda: next(_task_ids))
     submit_time: float = 0.0
     complete_time: Optional[float] = None
+    # on tracing.now_ns(), whatever clock the pool was given: the
+    # handoff span starts at complete_ns; a suspended task holds the
+    # acknowledgement's stamp and the id of its preemption span
+    complete_ns: int = 0
+    suspended_ns: int = 0
+    preempt_span: Optional[int] = None
 
     @property
     def head(self) -> Request:
@@ -82,6 +89,9 @@ class ExecutionPool:
     def resume(self, task_id: int) -> ExecTask:
         with self._cv:
             task = self._preempted.pop(task_id)
+        if on():
+            TRACER.record("prefill.suspended", task.suspended_ns, now_ns(),
+                          rid=rids(task.requests), parent=task.preempt_span)
         self.submit(task)
         return task
 
@@ -96,6 +106,11 @@ class ExecutionPool:
         acked = self.signal.wait_ack(timeout)
         with self._cv:
             if acked and task.task_id in self._preempted:
+                if on():
+                    signal_ns, ack_ns = self.signal.stamps
+                    task.preempt_span = TRACER.record(
+                        "prefill.preempt", signal_ns, ack_ns,
+                        rid=rids(task.requests), parent=TRACER.current())
                 return task
         # completed before the boundary check could suspend it
         self.signal.cancel()
@@ -139,8 +154,12 @@ class ExecutionPool:
     def _run(self) -> None:
         while True:
             with self._cv:
+                t_wait = 0
                 while self._current is None and not self._shutdown:
+                    t_wait = t_wait or now_ns()
                     self._cv.wait()
+                if t_wait and on():
+                    TRACER.record("prefill.wait", t_wait, now_ns())
                 if self._shutdown:
                     return
                 task = self._current
@@ -161,15 +180,25 @@ class ExecutionPool:
                 if self._on_error is not None:
                     self._on_error(task, exc)
 
+    @staticmethod
+    def _sync_span(task: ExecTask, what: str):
+        """Open the `prefill.sync` span of a wait on the device."""
+        return TRACER.begin("prefill.sync", rid=rids(task.requests),
+                            parent=task.head.trace_id, wait_for=what)
+
     def _run_task(self, task: ExecTask) -> None:
         window: List = []                      # dispatched, maybe unfinished
         while True:
             # cooperative preemption check at the operator boundary
             if self.signal.check():
                 # drain the in-flight operators (bounded by dispatch_depth)
+                sp = self._sync_span(task, "drain") if on() else None
                 jax.block_until_ready(task.prefill_task.state)
+                if sp is not None:
+                    TRACER.end(sp)
                 dt = self.signal.consume_and_ack()
                 self.blocking.record(dt)
+                task.suspended_ns = self.signal.stamps[1]
                 with self._cv:
                     self._preempted[task.task_id] = task
                     self._current = None
@@ -182,12 +211,19 @@ class ExecutionPool:
             if tok is not None:
                 window.append(tok)
                 if len(window) > self._dispatch_depth:
+                    sp = self._sync_span(task, "window") if on() else None
                     jax.block_until_ready(window.pop(0))
+                    if sp is not None:
+                        TRACER.end(sp)
 
             if done:
                 if task.prefill_task.logits is not None:
+                    sp = self._sync_span(task, "logits") if on() else None
                     jax.block_until_ready(task.prefill_task.logits)
+                    if sp is not None:
+                        TRACER.end(sp)
                 task.complete_time = self._clock()
+                task.complete_ns = now_ns()
                 with self._cv:
                     self._current = None
                 # unblock a racing preemption request (scheduler will see

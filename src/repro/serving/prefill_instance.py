@@ -32,6 +32,7 @@ from repro.core.events import Event, EventKind, EventMonitor
 from repro.core.prefixcache import block_keys
 from repro.core.request import Request, RequestState
 from repro.core.scheduler import Action, SchedulerCore
+from repro.core.tracing import TRACER, now_ns, on, rids
 from repro.models.model import on_device, params_device
 from repro.models.segments import SegmentedPrefill
 from repro.serving.kvcache import PagedKVCache
@@ -105,6 +106,7 @@ class PrefillInstance:
 
         # request bookkeeping (owned by the scheduler thread)
         self._tokens: Dict[int, np.ndarray] = {}
+        self._arrived_ns: Dict[int, int] = {}      # rid -> ARRIVAL stamp
         self._waiting: List[Request] = []
         self._running: Optional[ExecTask] = None
         self._preempted: Dict[int, ExecTask] = {}   # head rid -> task
@@ -125,6 +127,7 @@ class PrefillInstance:
     def submit_request(self, req: Request, tokens: np.ndarray) -> None:
         with self._lock:
             self._tokens[req.rid] = np.asarray(tokens)
+            self._arrived_ns[req.rid] = now_ns()
         self.monitor.publish(Event(time=self.clock(), kind=EventKind.ARRIVAL,
                                    payload=req))
 
@@ -204,8 +207,16 @@ class PrefillInstance:
 
     # ---------------------------------------------------------------- worker
     def _step(self, task: ExecTask) -> bool:
-        with on_device(self.device):
-            return self.executor.step(task.prefill_task)
+        sp = TRACER.begin("prefill.segment", rid=rids(task.requests),
+                          parent=task.head.trace_id,
+                          **self.executor.describe(task.prefill_task)) \
+            if on() else None
+        try:
+            with on_device(self.device):
+                return self.executor.step(task.prefill_task)
+        finally:
+            if sp is not None:
+                TRACER.end(sp)
 
     def _complete(self, task: ExecTask) -> None:
         if not self.healthy:
@@ -257,7 +268,12 @@ class PrefillInstance:
                     continue        # stranded: drop zombies until restart()
                 with self._lock:
                     self._handle_event(ev)
-                    self._round()
+                    sp = self._round_span(ev) if on() else None
+                    try:
+                        self._round(sp)
+                    finally:
+                        if sp is not None:
+                            TRACER.end(sp)
                     if not (self._waiting or self._preempted
                             or self._running is not None
                             or self.monitor.qsize() > 0):
@@ -411,13 +427,25 @@ class PrefillInstance:
             if self.on_prefill_done is not None:
                 self.on_prefill_done(task)
 
-    def _round(self) -> None:
+    @staticmethod
+    def _round_span(ev: Event):
+        """The `sched.round` span of the round an event triggers, caused by
+        the arriving request or the completed task's head."""
+        reqs = [ev.payload] if ev.kind == EventKind.ARRIVAL \
+            else ev.payload.requests
+        return TRACER.begin("sched.round", rid=rids(reqs),
+                            parent=reqs[0].trace_id, event=ev.kind.value)
+
+    def _round(self, sp=None) -> None:
         """One scheduling round (Alg. 2) + command execution."""
         now = self.clock()
         running_req = self._running.head if self._running is not None else None
         preempted_reqs = [t.head for t in self._preempted.values()]
         decision = self.scheduler.schedule_round(
             now, self._waiting, preempted_reqs, running_req)
+        if sp is not None:
+            sp.set(action=decision.action.value,
+                   preempt=decision.preempt is not None)
         if decision.is_noop:
             return
 
@@ -447,6 +475,12 @@ class PrefillInstance:
                              if r.rid not in waiting_ids]
             self._running = task
             self.pool.submit(task)
+            arrived = [self._arrived_ns.pop(r.rid, 0) for r in batch]
+            if on():
+                t = now_ns()
+                for r, t0 in zip(batch, arrived):
+                    TRACER.record("prefill.queue", t0 or t, t, rid=r.rid,
+                                  parent=r.trace_id)
         elif decision.action == Action.RESUME:
             head = decision.target
             task = self._preempted.pop(head.rid)

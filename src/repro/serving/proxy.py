@@ -42,6 +42,7 @@ from typing import (Callable, Dict, List, Optional, Sequence, Set, Tuple,
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.dispatch import (DispatchPolicy, InstanceLoad,
                                  competing_tokens, make_dispatch,
                                  plan_decode_migrations, predicted_ttft)
@@ -280,6 +281,11 @@ class Proxy:
         return loads
 
     def submit(self, req: Request, tokens: np.ndarray) -> None:
+        # the request span's start, stamped with or without a profiler
+        # session, so a request sent before a session opened is still
+        # recorded when it finishes inside it
+        req.submit_ns = tracing.now_ns()
+        req.trace_id = tracing.TRACER.new_id()
         tokens = np.asarray(tokens)
         now = self.clock()
         with self._load_lock:
@@ -619,6 +625,10 @@ class Proxy:
             }
             dec.submit(DecodeJob(request=req, cache=cache,
                                  first_token=int(first[i])))
+            if tracing.on():
+                tracing.TRACER.record("handoff", task.complete_ns,
+                                      tracing.now_ns(), rid=req.rid,
+                                      parent=req.trace_id)
         if self.decode_migration:
             self.rebalance_decodes()
 
