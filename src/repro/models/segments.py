@@ -7,6 +7,9 @@ boundary of a compiled computation, so we compile the prefill as a sequence of
 per-operator jitted segments over an explicit device-resident ExecState and let
 the host check the preemption flag between dispatches. Suspension keeps the
 state pytree alive on device (zero-copy); resume continues from the cursor.
+Each segment consumes (donates) the K/V cache and `h_full` it is given and
+returns them updated in place, so a caller holds only the newest state: a
+reference to a cache leaf taken before a `step` is dead after it.
 
 Operator sets (paper §5.1 / §6.5 exactly):
     dense:  qkv_proj | attn | o_proj | gate_up_proj | down_proj
@@ -46,6 +49,9 @@ State = Dict[str, Any]
 
 DENSE_OPS = ("qkv_proj", "attn", "o_proj", "gate_up_proj", "down_proj")
 MOE_OPS = ("qkv_proj", "attn", "o_proj", "gate", "experts")
+# the state's large buffers: donated to every segment program, which returns
+# them aliased (written in place or passed through, never copied)
+CACHE = ("k_cache", "v_cache", "h_full")
 
 
 def op_names(cfg: ModelConfig) -> Tuple[str, ...]:
@@ -204,13 +210,20 @@ class SegmentedPrefill:
             self._block_layers = cfg.num_layers
 
         def make_segment(fns, n_layers, name):
-            def seg(pl_, st, l0, off):
+            def seg(pl_, cache, act, l0, off, chunk_end=False):
+                st = dict(act, **cache)
                 for i in range(n_layers):
                     l = l0 + i
                     for f in fns:
                         st = f(pl_, st, l, off)
-                return st
-            return jit_named(f"prefill_{name}", seg)
+                if chunk_end:                   # the chunk's hidden rows
+                    st["h_full"] = lax.dynamic_update_slice(
+                        st["h_full"], st["h"].astype(st["h_full"].dtype),
+                        (0, off, 0))
+                return ({k: st[k] for k in CACHE},
+                        {"h": st["h"], "tmp": st["tmp"]})
+            return jit_named(f"prefill_{name}", seg, donate_argnums=1,
+                             static_argnames="chunk_end")
 
         self._segments = [make_segment(g, self._block_layers, name)
                           for g, name in zip(groups, self._group_names)]
@@ -328,18 +341,17 @@ class SegmentedPrefill:
         l0 = layer_block * self._block_layers
 
         st = task.state
+        h = st["h"]
         if within == 0:                                     # chunk begins: embed slice
             tokens = st["tokens"][:, lo:hi]
             ve = st.get("vision_embeds") if chunk_idx == 0 else None
-            st = dict(st, h=self._start_fn(self.params, tokens, ve))
-        layer_params = (self.params["layers"])
-        st = self._segments[group_idx](layer_params, st, l0, lo)
-        if within == spc - 1:                               # chunk ends
-            hf = lax.dynamic_update_slice(
-                st["h_full"], st["h"].astype(st["h_full"].dtype), (0, lo, 0))
-            st = dict(st, h_full=hf)
-        task.state = st
-        task.sync_token = st["h"] if st.get("h") is not None else st["h_full"]
+            h = self._start_fn(self.params, tokens, ve)
+        cache, act = self._segments[group_idx](
+            self.params["layers"], {k: st[k] for k in CACHE},
+            {"h": h, "tmp": st["tmp"]}, l0, lo,
+            chunk_end=within == spc - 1)
+        task.state = dict(st, **cache, **act)
+        task.sync_token = act["h"]
         task.cursor += 1
         return task.done
 

@@ -20,6 +20,7 @@ from repro.kernels.decode_attn import (flash_decode_attention,
 from repro.kernels.flash_prefill import flash_prefill_attention
 from repro.models import model as M
 from repro.models.segments import SegmentedPrefill
+from test_segments import cache_aliased, copied_shapes
 
 CFG = get_config("qwen2_1_5b")
 H, K, HD = CFG.num_heads, CFG.num_kv_heads, CFG.resolved_head_dim
@@ -101,20 +102,23 @@ def test_prefill_attn_segment_compiles(one_chip):
                           M.model_shapes(CFG)["layers"],
                           is_leaf=lambda x: isinstance(x, tuple))
     cache = _spec((CFG.num_layers, B, max_seq, K, HD), f32, one_chip)
-    state = {
-        "tokens": _spec((B, max_seq), jnp.int32, one_chip),
-        "lens": _spec((B,), jnp.int32, one_chip),
-        "h": _spec((B, chunk, CFG.d_model), f32, one_chip),
-        "tmp": (_spec((B, chunk, H, HD), f32, one_chip),
-                _spec((B, chunk, K, HD), f32, one_chip),
-                _spec((B, chunk, K, HD), f32, one_chip)),
+    caches = {
         "k_cache": cache,
         "v_cache": cache,
         "h_full": _spec((B, max_seq, CFG.d_model), f32, one_chip),
     }
+    act = {
+        "h": _spec((B, chunk, CFG.d_model), f32, one_chip),
+        "tmp": (_spec((B, chunk, H, HD), f32, one_chip),
+                _spec((B, chunk, K, HD), f32, one_chip),
+                _spec((B, chunk, K, HD), f32, one_chip)),
+    }
     scalar = _spec((), jnp.int32, one_chip)
     attn = ex._segments[ex.ops.index("attn")]
-    compiled = attn.lower(layers, state, scalar, scalar).compile()
+    compiled = attn.lower(layers, caches, act, scalar, scalar).compile()
     _assert_kernel(compiled)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    # the cache is written in place: donated, aliased, never copied
+    assert cache_aliased(compiled)
+    assert not {cache.shape, caches["h_full"].shape} & copied_shapes(compiled)

@@ -19,7 +19,7 @@ from repro.core import Request, SchedulerCore, TTFTPredictor
 from repro.core import tracing
 from repro.core.tracing import TRACER
 from repro.models import init_params
-from repro.models.segments import SegmentedPrefill
+from repro.models.segments import CACHE, SegmentedPrefill
 from repro.serving.decode_instance import DecodeInstance
 from repro.serving.prefill_instance import PrefillInstance
 from repro.serving.proxy import Proxy
@@ -230,15 +230,16 @@ def test_device_programs_carry_stable_names():
     ex = SegmentedPrefill(params, CFG, max_seq=128, granularity="op",
                           chunk_tokens=64)
     task = ex.start(jnp.zeros((1, 64), jnp.int32))
-    st = dict(task.state, h=ex._start_fn(params, task.state["tokens"]))
+    cache = {k: task.state[k] for k in CACHE}
+    act = {"h": ex._start_fn(params, task.state["tokens"]), "tmp": None}
     for seg, op in zip(ex._segments, ex.ops):
-        text = seg.lower(params["layers"], st, 0, 0).as_text()
+        text = seg.lower(params["layers"], cache, act, 0, 0).as_text()
         assert f"@jit_prefill_{op}" in text
-        st = seg(params["layers"], st, 0, 0)
+        cache, act = seg(params["layers"], cache, act, 0, 0)
     assert "@jit_prefill_embed" in ex._start_fn.lower(
         params, task.state["tokens"]).as_text()
     assert "@jit_prefill_head" in ex._head_fn.lower(
-        params, task.state["h_full"], task.state["lens"]).as_text()
+        params, cache["h_full"], task.state["lens"]).as_text()
     assert SegmentedPrefill(params, CFG, max_seq=128, granularity="layer") \
         ._segments[0].__name__ == "prefill_layer"
     dec = DecodeInstance(params, CFG, decode_max_batch=2,
