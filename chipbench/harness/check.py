@@ -3,9 +3,10 @@
 Once the window has closed and the program is shut down, a sample of the
 requests it finished (drawn from the seed, the longest always in it, and
 every request that was preempted mid-prompt and resumed) is run through the
-float32 reference over its prompt and the tokens the program served. At each
-served position the gap is the reference's best logit minus the logit of the
-token the program put there; the number compared is the widest gap.
+family's float32 reference (`families/<family>.py` `logits_at`) over its
+prompt and the tokens the program served. At each served position the gap
+is the reference's best logit minus the logit of the token the program put
+there; the number compared is the widest gap.
 Greedy decoding puts the argmax first, so a correct bfloat16 path stays
 within rounding of the reference's best, and a wrong mask, position, cache
 row or handoff does not.
@@ -20,8 +21,6 @@ from dataclasses import dataclass
 from typing import Dict, List, Sequence
 
 import numpy as np
-
-from harness import reference
 
 
 @dataclass
@@ -57,18 +56,19 @@ def sample(served: Sequence[Served], seed: int, budget: int) -> List[Served]:
     return pick
 
 
-def gaps(params, cfg: dict, s: Served, mode: str = "f32") -> Dict[str, np.ndarray]:
+def gaps(fam, params, cfg: dict, s: Served,
+         mode: str = "f32") -> Dict[str, np.ndarray]:
     """Per served position: the f32 reference's best logit minus the logit
     of the served token (`served`), and with mode "fp8" also minus the logit
     of the token the control puts first (`control`)."""
     toks = np.concatenate([s.prompt, np.asarray(s.tokens[:-1], np.int32)])
     n = len(s.prompt)
     pos = np.arange(n - 1, n - 1 + len(s.tokens))
-    ref = reference.logits_at(params, cfg, toks, pos, "f32")
+    ref = fam.logits_at(params, cfg, toks, pos, "f32")
     best = ref.max(axis=-1)
     rows = np.arange(len(pos))
     out = {"served": best - ref[rows, np.asarray(s.tokens)]}
     if mode == "fp8":
-        low = reference.logits_at(params, cfg, toks, pos, "fp8")
+        low = fam.logits_at(params, cfg, toks, pos, "fp8")
         out["control"] = best - ref[rows, low.argmax(axis=-1)]
     return out

@@ -1,74 +1,83 @@
 """Operations and bytes the served work needs, from shapes alone.
 
 `cfg` is a benchmark configuration (`chipbench/configs/<name>.json`): the
-published sizes under their published keys. Every count is the algorithm's,
-not the program's: padding, recomputation and copies do not count.
+published sizes under their published keys. `fam` is its family module
+(`chipbench/families/<family>.py`), which gives the counts that depend on
+the architecture: `token_flops(cfg)`, the projections and MLP or experts of
+one token through all layers (only the experts a token is routed to);
+`head_flops(cfg)`, the output projection at one position; and
+`attention(cfg)`, one `Attn` per attention layer. Every count is the
+algorithm's, not the program's: padding, recomputation and copies do not
+count.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 
-def dims(cfg: dict) -> dict:
-    d = int(cfg["hidden_size"])
-    heads = int(cfg["num_attention_heads"])
-    return {"d": d, "L": int(cfg["num_hidden_layers"]), "H": heads,
-            "K": int(cfg["num_key_value_heads"]),
-            "hd": int(cfg.get("head_dim") or d // heads),
-            "F": int(cfg["intermediate_size"]), "V": int(cfg["vocab_size"])}
+class Attn(NamedTuple):
+    """One attention layer: query heads, key/value heads, head size, and
+    the keys each query reads: all before it (window 0), or the `window`
+    positions ending at its own."""
+    heads: int
+    kv_heads: int
+    head_dim: int
+    window: int = 0
 
 
-def layer_matmul_flops(cfg: dict) -> int:
-    """Multiply-adds x 2 of one layer's projections for one token: q, k, v,
-    o and the gated MLP (gate, up, down)."""
-    m = dims(cfg)
-    d, H, K, hd, F = m["d"], m["H"], m["K"], m["hd"], m["F"]
-    return 2 * (d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * F)
-
-
-def head_flops(cfg: dict) -> int:
-    """The output projection for one position."""
-    m = dims(cfg)
-    return 2 * m["d"] * m["V"]
-
-
-def attn_pairs_prefill(offset: int, n: int) -> int:
+def attn_pairs_prefill(offset: int, n: int, window: int = 0) -> int:
     """Query-key pairs of n causal queries at positions offset..offset+n-1,
-    each over the keys at and before it."""
-    return n * offset + n * (n + 1) // 2
+    each over the keys at and before it (the last `window` of them, where
+    window > 0)."""
+    full = n * offset + n * (n + 1) // 2
+    if window <= 0:
+        return full
+    # positions p >= window read `window` keys instead of p + 1
+    over = max(offset + n - max(offset, window), 0)
+    first = max(offset, window)
+    return full - (over * (first + 1) + over * (over - 1) // 2) + over * window
 
 
-def attn_flops(cfg: dict, pairs: int) -> int:
-    """QK^T and PV over `pairs` query-key pairs, all layers and heads."""
-    m = dims(cfg)
-    return 4 * m["L"] * m["H"] * m["hd"] * pairs
+def attn_keys(offset: int, n: int, window: int = 0) -> int:
+    """Keys that n queries at offset..offset+n-1 read between them."""
+    if window <= 0:
+        return offset + n
+    return offset + n - max(offset - window + 1, 0)
 
 
-def prefill_flops(cfg: dict, prompt_len: int) -> int:
+def attn_flops(fam, cfg: dict, offset: int, n: int) -> int:
+    """QK^T and PV of n causal queries at offset, all attention layers and
+    heads."""
+    return sum(4 * a.heads * a.head_dim
+               * attn_pairs_prefill(offset, n, a.window)
+               for a in fam.attention(cfg))
+
+
+def prefill_flops(fam, cfg: dict, prompt_len: int) -> int:
     """A whole prompt: every token through every layer, causal attention,
     and the output projection at the last position (the first token)."""
-    m = dims(cfg)
-    return (prompt_len * m["L"] * layer_matmul_flops(cfg)
-            + attn_flops(cfg, attn_pairs_prefill(0, prompt_len))
-            + head_flops(cfg))
+    return (prompt_len * fam.token_flops(cfg)
+            + attn_flops(fam, cfg, 0, prompt_len) + fam.head_flops(cfg))
 
 
-def decode_flops(cfg: dict, ctx: int) -> int:
-    """One decoded token at position ctx (it attends ctx + 1 keys)."""
-    m = dims(cfg)
-    return (m["L"] * layer_matmul_flops(cfg) + attn_flops(cfg, ctx + 1)
-            + head_flops(cfg))
+def decode_flops(fam, cfg: dict, ctx: int) -> int:
+    """One decoded token at position ctx (it attends ctx + 1 keys, or its
+    window)."""
+    return (fam.token_flops(cfg) + attn_flops(fam, cfg, ctx, 1)
+            + fam.head_flops(cfg))
 
 
-def flash_prefill_cost(cfg: dict, offset: int, n: int,
+def flash_prefill_cost(a: Attn, offset: int, n: int,
                        itemsize: int = 2) -> Tuple[int, int]:
-    """(flops, bytes) of one prefill attention call for ONE layer: n queries
-    at offset over their causal context. Bytes: q read, output written, and
-    the K and V of the real context (offset + n positions) read once."""
-    m = dims(cfg)
-    H, K, hd = m["H"], m["K"], m["hd"]
-    flops = 4 * H * hd * attn_pairs_prefill(offset, n)
-    nbytes = itemsize * (2 * n * H * hd + 2 * (offset + n) * K * hd)
+    """(flops, bytes) of one prefill attention call for ONE layer `a`: n
+    queries at offset over their causal context. Bytes: q read, output
+    written, and the K and V of the real context (the keys the queries
+    read) read once."""
+    flops = 4 * a.heads * a.head_dim * attn_pairs_prefill(offset, n,
+                                                          a.window)
+    nbytes = itemsize * (2 * n * a.heads * a.head_dim
+                         + 2 * attn_keys(offset, n, a.window)
+                         * a.kv_heads * a.head_dim)
     return flops, nbytes
 
 

@@ -24,8 +24,9 @@ def decode_rows(run) -> List[Tuple[int, List[int]]]:
 def step_mfu(run) -> float:
     """Model FLOPs of the window's served work over the device's busy time
     at the bf16 peak, in percent."""
-    total = sum(flops.prefill_flops(run.cfg, n) for n in prompts(run))
-    total += sum(flops.decode_flops(run.cfg, p)
+    total = sum(flops.prefill_flops(run.family, run.cfg, n)
+                for n in prompts(run))
+    total += sum(flops.decode_flops(run.family, run.cfg, p)
                  for _, pos in decode_rows(run) for p in pos)
     if total == 0 or run.busy_s <= 0:
         return None

@@ -3,7 +3,8 @@
 
     python chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-One process: make the weights from the seed, build the deployment
+One process: make the weights from the seed (in the layout of the
+configuration's family, `families/<family>.py`), build the deployment
 (`harness.serving`), warm every shape the cell's traffic uses, send the
 traffic (`harness.traffic`) for a lead-in and then the measured window,
 wait for the window's requests, check what was served against the float32
@@ -23,9 +24,7 @@ Options for calibration, not used by the benchmark's own runs:
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import gc
-import importlib
 import json
 import math
 import os
@@ -71,28 +70,6 @@ def setup_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path
-
-
-def program_config(cfg: dict):
-    """The program's ModelConfig for a benchmark configuration: the module
-    the file names, with every size taken from the file."""
-    mod = importlib.import_module(f"repro.configs.{cfg['module']}")
-    pc = dataclasses.replace(
-        mod.CONFIG,
-        num_layers=int(cfg["num_hidden_layers"]),
-        d_model=int(cfg["hidden_size"]),
-        num_heads=int(cfg["num_attention_heads"]),
-        num_kv_heads=int(cfg["num_key_value_heads"]),
-        d_ff=int(cfg["intermediate_size"]),
-        vocab_size=int(cfg["vocab_size"]),
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        qkv_bias=True)
-    if pc.family != "dense" or pc.num_experts or pc.local_window:
-        raise ValueError(f"{cfg['module']} is not a dense full-attention "
-                         f"stack; this harness serves only those")
-    return pc
 
 
 def check_layout(params, pcfg) -> None:
@@ -309,11 +286,12 @@ def execute(args, bench, wl, cfg, mix, cell, attn):
     devs = jax.devices()
     dev = devs[0]
     seed = args.seed & ((1 << 63) - 1)
-    pcfg = program_config(cfg)
+    fam = spec.family(cfg["family"], BENCH_DIR)
+    pcfg = fam.program_config(cfg)
     if int(cell["max_seq"]) > int(cfg["max_position_embeddings"]):
         raise ValueError(f"cell max_seq {cell['max_seq']} exceeds the "
                          f"configuration's max_position_embeddings")
-    params = weights.make(cfg, seed, jnp.bfloat16)
+    params = weights.make(fam, cfg, seed, jnp.bfloat16)
     jax.block_until_ready(params)
     check_layout(params, pcfg)
     log(f"weights: {sum(x.size for x in jax.tree.leaves(params))} params "
@@ -358,7 +336,8 @@ def execute(args, bench, wl, cfg, mix, cell, attn):
     t_ref = time.monotonic()
     program_gap, control_gap, n_tok = 0.0, 0.0, 0
     for s in sample:
-        g = check.gaps(params, cfg, s, "fp8" if args.control else "f32")
+        g = check.gaps(fam, params, cfg, s,
+                       "fp8" if args.control else "f32")
         program_gap = max(program_gap, float(g["served"].max()))
         n_tok += len(s.tokens)
         if args.control:
@@ -393,7 +372,7 @@ def execute(args, bench, wl, cfg, mix, cell, attn):
               "count": len(devs), "memory_peak_bytes": memory_peak}
     breakdown = None
     if args.trace:
-        from harness import trace as tr
+        from harness import trace as tr, work
         planes = tr.load(tr.find_xplane(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
         dt = planes[f"/device:TPU:{dev.id}"]
@@ -401,8 +380,13 @@ def execute(args, bench, wl, cfg, mix, cell, attn):
         device.update(busy_s=busy_s, window_s=run.seconds)
         breakdown = {"device_ops": [[k, v] for k, v in tr.top_ops(dt)],
                      "idle_gaps": [[k, v] for k, v in tr.idle_gaps(dt)]}
-        r = Run(**run.__dict__, cfg=cfg, cell=cell, device_trace=dt,
-                busy_s=busy_s, peaks=spec.peaks(dev.device_kind))
+        r = Run(**run.__dict__, cfg=cfg, family=fam, cell=cell,
+                device_trace=dt, busy_s=busy_s,
+                peaks=spec.peaks(dev.device_kind))
+        rows = work.decode_rows(r)
+        log(f"counted work: prompts whose first token came in the window "
+            f"{work.prompts(r)}; {sum(len(p) for _, p in rows)} decode rows "
+            f"in {len(rows)} steps; busy_s={busy_s!r}")
         for name, m in spec.metrics_for(bench, wl["name"],
                                         "per_layer").items():
             v = spec.metric_reader(name)(r)

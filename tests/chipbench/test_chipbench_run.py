@@ -1,12 +1,14 @@
 """The harness's entry: it refuses to run without a TPU or without the
-program's sources, and finds configurations, traffic mixes and metrics by
-name, so that adding one is adding a file."""
+program's sources, and finds configurations, families, traffic mixes and
+metrics by name, so that adding one is adding a file."""
 import json
 import os
 import shutil
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 from conftest import BENCH
 from harness import spec, traffic
@@ -81,6 +83,10 @@ def test_every_listed_name_has_its_file():
     for c in bench["configs"]:
         assert (ROOT / c["file"]).is_file()
         assert spec.config(c["name"])["name"] == c["name"]
+        fam = spec.family(spec.config(c["name"])["family"])
+        assert all(callable(getattr(fam, f)) for f in (
+            "program_config", "shapes", "distribution", "logits_at",
+            "token_flops", "head_flops", "attention"))
         assert sorted(spec.config(c["name"])["reduced"]) == \
             sorted(c["reduced"])
     for w in bench["workloads"]:
@@ -99,6 +105,24 @@ def test_every_listed_name_has_its_file():
     assert names["traffic"] == {w["traffic"] for w in bench["workloads"]}
     assert names["cells"] == {w["name"] for w in bench["workloads"]}
     assert names["metrics"] == {m["name"] for m in bench["per_layer"]}
+
+
+@pytest.mark.parametrize("change,says", [
+    ({"family": None}, r"has no `family` key"),
+    ({"family": "alien"}, r"no family 'alien' \(no families/alien.py\)"),
+])
+def test_a_configuration_without_its_family_is_an_error(tmp_path, change,
+                                                         says):
+    copy = tmp_path / "chipbench"
+    shutil.copytree(BENCH, copy, ignore=shutil.ignore_patterns("__pycache__"))
+    cfg = {k: v for k, v in dict(spec.config("qwen2-1.5b"), **change).items()
+           if v is not None}
+    (copy / "configs" / "probe.json").write_text(json.dumps(cfg))
+    with pytest.raises(KeyError, match=says + r"; the families are "
+                       r"\['qwen2'\]"):
+        spec.config("probe", copy)
+    with pytest.raises(KeyError, match=r"no family 'alien'.*\['qwen2'\]"):
+        spec.family("alien", copy)
 
 
 def test_unknown_device_kind_is_an_error():
